@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch import compile_cache
+
 #: structured results (filled as benches run; dumped by --json)
 _JSON: dict = {}
 
@@ -755,6 +757,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main() -> None:
     args = build_parser().parse_args()
+    compile_cache.enable()
 
     benches = [
         ("coalition_round_n10_d582k", bench_coalition_round),

@@ -53,9 +53,8 @@ def _pairwise_sq_dot(w: jax.Array) -> jax.Array:
     (N, N) matrix instead of an all-gather of the full weight matrix (see
     EXPERIMENTS.md §Perf, FL round)."""
     instrument.count_w_pass()
-    wf = w.astype(jnp.float32)
-    gram = wf @ wf.T
-    sq = jnp.sum(wf * wf, axis=1)
+    gram = fz.centered_gram(w.astype(jnp.float32))
+    sq = jnp.diagonal(gram)
     d2 = sq[:, None] + sq[None, :] - 2.0 * gram
     n = w.shape[0]
     return jnp.maximum(d2, 0.0) * (1.0 - jnp.eye(n, dtype=jnp.float32))
@@ -85,7 +84,9 @@ def _to_points_sq_xla(w: jax.Array, points: jax.Array, chunk: int) -> jax.Array:
 def _to_points_sq_dot(w: jax.Array, points: jax.Array) -> jax.Array:
     instrument.count_w_pass()
     wf, pf = w.astype(jnp.float32), points.astype(jnp.float32)
-    cross = wf @ pf.T
+    r = jnp.mean(wf, axis=0, keepdims=True)          # see fz.centered_gram
+    wf, pf = wf - r, pf - r
+    cross = fz.mm(wf, pf.T)
     d2 = (jnp.sum(wf * wf, 1)[:, None] + jnp.sum(pf * pf, 1)[None, :]
           - 2.0 * cross)
     return jnp.maximum(d2, 0.0)
@@ -94,7 +95,7 @@ def _to_points_sq_dot(w: jax.Array, points: jax.Array) -> jax.Array:
 def _segment_sum_matmul(onehot: jax.Array, w: jax.Array) -> jax.Array:
     """(K, N) one-hot × (N, D) weights — MXU does the segment reduction."""
     instrument.count_w_pass()
-    return onehot @ w.astype(jnp.float32)
+    return fz.mm(onehot, w.astype(jnp.float32))
 
 
 bk.register_backend(bk.Backend(

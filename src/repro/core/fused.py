@@ -78,6 +78,15 @@ class FusedRound(NamedTuple):
                               # (sketch-space under a sketcher, like radius)
 
 
+#: Every geometry matmul runs at full f32 precision.  A default-precision f32
+#: dot on the TPU rounds its operands to bf16: the Gram form
+#: ‖w‖² + ‖c‖² − 2⟨w, c⟩ then cancels catastrophically (clients start each
+#: round from one θ, so their distances are tiny next to their norms), and
+#: barycenters keep ~3 significant digits.  The CPU computes f32 dots exactly
+#: either way, so this changes no CPU result.
+mm = functools.partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
+
+
 # --- sweep chunk size ------------------------------------------------------------
 
 #: cap on the streaming sweep tile: (N, 64k) f32 chunks keep the resident
@@ -148,13 +157,23 @@ def aggregation_matrix(assignment: jax.Array, k: int, center_idx: jax.Array,
     return oh_eff, counts, denom
 
 
+#: Members within this relative margin of the smallest distance to their
+#: barycenter tie, and the lowest client index among them is elected.  Exact
+#: ties are structural (both members of an equal-weight two-client coalition
+#: sit at the same distance from their midpoint), and f32 roundoff, which
+#: differs between backends, chunk widths and mesh shards, must not decide
+#: them.  The margin is far above that roundoff (~1e-6 relative).
+MEDOID_TIE_RTOL = 1e-4
+
+
 def medoid_from_d2(med_d2: jax.Array, assignment: jax.Array,
                    client_weights: jax.Array | None = None) -> jax.Array:
     """Step III center update from accumulated client->barycenter distances.
 
     Restricted to members of each coalition; zero-mass clients (participation
     mask 0 under ``semi_async``) are not electable — a center that contributed
-    nothing to the barycenter must not anchor next round's assignment.  Falls
+    nothing to the barycenter must not anchor next round's assignment.  Near
+    ties go to the lowest index (:data:`MEDOID_TIE_RTOL`).  Falls
     back to the global argmin when a coalition has no positive-mass member so
     the returned index stays valid.
     """
@@ -163,13 +182,25 @@ def medoid_from_d2(med_d2: jax.Array, assignment: jax.Array,
     if client_weights is not None:
         member = member & (client_weights > 0)[:, None]
     masked = jnp.where(member, med_d2, jnp.inf)
+    best = jnp.min(masked, axis=0)                                   # (K,)
+    tied = masked <= best * (1.0 + MEDOID_TIE_RTOL)
     any_member = jnp.any(member, axis=0)
-    idx = jnp.where(any_member, jnp.argmin(masked, axis=0),
+    idx = jnp.where(any_member, jnp.argmax(tied, axis=0),
                     jnp.argmin(med_d2, axis=0))
     return idx.astype(jnp.int32)
 
 
 # --- xla: lax.scan streaming composition ----------------------------------------
+
+def _zeros_like_rows(w: jax.Array, k: int) -> jax.Array:
+    """(N, k) f32 zeros that inherit ``w``'s mesh-varying axes.
+
+    Under ``shard_map`` a scan carry must vary over the same mesh axes as the
+    body's output; deriving the initial accumulator from the (D-sharded) W
+    tile makes it so, and outside ``shard_map`` it is plain zeros.
+    """
+    return jnp.zeros_like(w, shape=(w.shape[0], k), dtype=jnp.float32)
+
 
 def _xla_center_d2(w: jax.Array, center_idx: jax.Array, chunk: int) -> jax.Array:
     """Pass 1: (N, K) assignment distances, center rows read out of each chunk.
@@ -179,7 +210,7 @@ def _xla_center_d2(w: jax.Array, center_idx: jax.Array, chunk: int) -> jax.Array
     to the composed path — but W is sliced in place (``dynamic_slice``), never
     transposed or re-materialised, and the (K, D) center gather never exists.
     """
-    n, d = w.shape
+    d = w.shape[1]
     k = center_idx.shape[0]
     nfull, tail = divmod(d, chunk)
 
@@ -188,7 +219,7 @@ def _xla_center_d2(w: jax.Array, center_idx: jax.Array, chunk: int) -> jax.Array
         diff = wk[:, None, :] - pk[None, :, :]
         return acc + jnp.sum(diff * diff, axis=-1)
 
-    acc = jnp.zeros((n, k), jnp.float32)
+    acc = _zeros_like_rows(w, k)
     if nfull:
         def body(carry, i):
             wk = jax.lax.dynamic_slice_in_dim(
@@ -206,17 +237,17 @@ def _xla_center_d2(w: jax.Array, center_idx: jax.Array, chunk: int) -> jax.Array
 def _xla_bary_med_theta(w: jax.Array, oh_eff: jax.Array, denom: jax.Array,
                         chunk: int) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Pass 2: barycenters + θ tiles emitted per chunk, medoid d² accumulated."""
-    n, d = w.shape
+    d = w.shape[1]
     k = oh_eff.shape[0]
     nfull, tail = divmod(d, chunk)
 
     def emit(acc, wk):
-        bc = (oh_eff @ wk) / denom[:, None]                  # (K, c)
+        bc = mm(oh_eff, wk) / denom[:, None]                 # (K, c)
         tc = jnp.mean(bc, axis=0)                            # (c,)
         diff = wk[:, None, :] - bc[None, :, :]
         return acc + jnp.sum(diff * diff, axis=-1), bc, tc
 
-    acc = jnp.zeros((n, k), jnp.float32)
+    acc = _zeros_like_rows(w, k)
     b_parts, t_parts = [], []
     if nfull:
         def body(carry, i):
@@ -252,7 +283,7 @@ def _xla_bary_theta(w: jax.Array, oh_eff: jax.Array, denom: jax.Array,
     nfull, tail = divmod(d, chunk)
 
     def emit(wk):
-        bc = (oh_eff @ wk) / denom[:, None]                  # (K, c)
+        bc = mm(oh_eff, wk) / denom[:, None]                 # (K, c)
         return bc, jnp.mean(bc, axis=0)
 
     b_parts, t_parts = [], []
@@ -295,27 +326,40 @@ def fused_round_xla(w: jax.Array, center_idx: jax.Array, *,
 
 # --- dot: Gram composition -------------------------------------------------------
 
+def centered_gram(wf: jax.Array) -> jax.Array:
+    """(N, N) Gram matrix of the rows of ``wf`` less their column mean.
+
+    Distances are translation-invariant, and centered rows are as small as
+    the distances between them: clients that start a round from one θ sit
+    close together far from the origin, and the uncentered Gram form
+    ‖w_i‖² + ‖w_j‖² − 2⟨w_i, w_j⟩ would cancel away most of f32's digits.
+    """
+    wc = wf - jnp.mean(wf, axis=0, keepdims=True)
+    return mm(wc, wc.T)
+
+
 def fused_round_dot(w: jax.Array, center_idx: jax.Array, *,
                     client_weights: jax.Array | None = None, **_) -> FusedStats:
     """Gram form: with W sharded over D the pass-1 contraction shrinks to an
     (N, N) all-reduce, and the medoid distances are pure Gram algebra —
     ⟨w_i, b_j⟩ = (G · M^T)_ij / denom_j — so only the segment matmul (pass 2)
-    re-reads W."""
+    re-reads W.  G is the :func:`centered_gram`; each row of M / denom sums
+    to one, so the barycenters it implies are centered by the same mean."""
     k = center_idx.shape[0]
     wf = w.astype(jnp.float32)
     instrument.count_w_pass()                                # pass 1
-    gram = wf @ wf.T                                         # (N, N)
-    sq = jnp.diagonal(gram)                                  # ‖w_i‖²
+    gram = centered_gram(wf)                                 # (N, N)
+    sq = jnp.diagonal(gram)                                  # ‖w_i − w̄‖²
     d2c = jnp.maximum(sq[:, None] + sq[center_idx][None, :]
                       - 2.0 * gram[:, center_idx], 0.0)
     assignment = pin_assignment(d2c, center_idx)
     oh_eff, counts, denom = aggregation_matrix(assignment, k, center_idx,
                                                client_weights)
     instrument.count_w_pass()                                # pass 2
-    b = (oh_eff @ wf) / denom[:, None]
+    b = mm(oh_eff, wf) / denom[:, None]
     theta = jnp.mean(b, axis=0)
-    cross = (gram @ oh_eff.T) / denom[None, :]               # (N, K) ⟨w_i, b_j⟩
-    bsq = jnp.diagonal(oh_eff @ gram @ oh_eff.T) / (denom * denom)
+    cross = mm(gram, oh_eff.T) / denom[None, :]              # (N, K) ⟨w_i, b_j⟩
+    bsq = jnp.diagonal(mm(mm(oh_eff, gram), oh_eff.T)) / (denom * denom)
     med_d2 = jnp.maximum(sq[:, None] + bsq[None, :] - 2.0 * cross, 0.0)
     return FusedStats(assignment=assignment, barycenters=b, counts=counts,
                       med_d2=med_d2, theta=theta)
@@ -396,7 +440,7 @@ def sketch_stage(backend: bk.Backend, s_w: jax.Array, center_idx: jax.Array, *,
         assignment = pin_assignment(d2c, center_idx)
         oh_eff, counts, denom = aggregation_matrix(assignment, k, center_idx,
                                                    client_weights)
-        s_b = (oh_eff @ s_w.astype(jnp.float32)) / denom[:, None]    # (K, S)
+        s_b = mm(oh_eff, s_w.astype(jnp.float32)) / denom[:, None]   # (K, S)
         med_d2 = backend.sq_dists_to_points(s_w, s_b)
     return assignment, oh_eff, counts, denom, med_d2
 

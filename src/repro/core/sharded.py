@@ -45,7 +45,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import backends as bk
 from repro.core import fused as fz
 from repro.core import instrument
@@ -76,20 +75,22 @@ def _local_xla(w_loc, center_idx, client_weights, *, chunk, axis):
 
 def _local_dot(w_loc, center_idx, client_weights, *, chunk, axis):
     """Gram form: the pass-1 collective is the (C, C) partial-Gram psum —
-    exactly the D-sharding this backend was built for."""
+    exactly the D-sharding this backend was built for.  Each tile centers
+    its own columns; the mean is per column, so the psum of the tiles'
+    centered Grams is the whole centered Gram."""
     instrument.count_w_pass()                                # pass 1 (local)
     wf = w_loc.astype(jnp.float32)
-    gram = jax.lax.psum(wf @ wf.T, axis)                     # (C, C)
+    gram = jax.lax.psum(fz.centered_gram(wf), axis)          # (C, C)
     sq = jnp.diagonal(gram)
     d2c = jnp.maximum(sq[:, None] + sq[center_idx][None, :]
                       - 2.0 * gram[:, center_idx], 0.0)
     assignment, oh_eff, counts, denom = _finish_pass1(
         d2c, center_idx, client_weights)
     instrument.count_w_pass()                                # pass 2 (local)
-    b = (oh_eff @ wf) / denom[:, None]                       # (K, D/p) tile
+    b = fz.mm(oh_eff, wf) / denom[:, None]                   # (K, D/p) tile
     theta = jnp.mean(b, axis=0)                              # (D/p,) tile
-    cross = (gram @ oh_eff.T) / denom[None, :]
-    bsq = jnp.diagonal(oh_eff @ gram @ oh_eff.T) / (denom * denom)
+    cross = fz.mm(gram, oh_eff.T) / denom[None, :]
+    bsq = jnp.diagonal(fz.mm(fz.mm(oh_eff, gram), oh_eff.T)) / (denom * denom)
     med_d2 = jnp.maximum(sq[:, None] + bsq[None, :] - 2.0 * cross, 0.0)
     return fz.FusedStats(assignment=assignment, barycenters=b, counts=counts,
                          med_d2=med_d2, theta=theta)
@@ -115,9 +116,12 @@ def _local_pallas(w_loc, center_idx, client_weights, *, chunk, axis):
 
 _LOCALS = {"xla": _local_xla, "dot": _local_dot, "pallas": _local_pallas}
 
-#: pallas_call has no shard_map replication rule, so the pallas body runs
-#: with the replication checker off; its P() outputs are still genuinely
-#: replicated (they come out of the same psums as the xla body).
+#: The pallas body runs with the varying-axes checker off: a checked
+#: ``shard_map`` needs every pallas_call output typed with its ``vma``, and
+#: the kernel bodies then still mix the replicated (K, N) operand with the
+#: mesh-varying W tile in one dot, which the checker rejects (jax 0.9).  Its
+#: P() outputs are still genuinely replicated (they come out of the same
+#: psums as the xla body).
 _UNCHECKED = frozenset({"pallas"})
 
 #: specs of a FusedStats coming out of the per-shard body: assignment /
@@ -135,7 +139,7 @@ def _pass2_xla(w_loc, oh_eff, denom, *, chunk):
 
 
 def _pass2_dot(w_loc, oh_eff, denom, *, chunk):
-    b = (oh_eff @ w_loc.astype(jnp.float32)) / denom[:, None]
+    b = fz.mm(oh_eff, w_loc.astype(jnp.float32)) / denom[:, None]
     return b, jnp.mean(b, axis=0)
 
 
@@ -169,7 +173,7 @@ def _local_sketched(pass2, sketcher, w_loc, center_idx, client_weights, *,
     d2c = _sq_to_points(s_w, s_w[center_idx])
     assignment, oh_eff, counts, denom = _finish_pass1(
         d2c, center_idx, client_weights)
-    s_b = (oh_eff @ s_w) / denom[:, None]                    # (K, S)
+    s_b = fz.mm(oh_eff, s_w) / denom[:, None]                # (K, S)
     med_d2 = _sq_to_points(s_w, s_b)
     instrument.count_w_pass()                    # bary/θ sweep (local tile)
     b, theta = pass2(w_loc, oh_eff, denom, chunk=chunk)
@@ -188,12 +192,12 @@ def _sharded_sketched_round(base_name, mesh, axis, check, w, center_idx, *,
                    axis=axis)
     out_specs = stats_specs(axis)
     if client_weights is None:
-        f = shard_map(lambda wl, ci: body(wl, ci, None), mesh=mesh,
+        f = jax.shard_map(lambda wl, ci: body(wl, ci, None), mesh=mesh,
                       in_specs=(P(None, axis), P()), out_specs=out_specs,
                       check_vma=check)
         s = f(wp, center_idx)
     else:
-        f = shard_map(body, mesh=mesh,
+        f = jax.shard_map(body, mesh=mesh,
                       in_specs=(P(None, axis), P(), P()), out_specs=out_specs,
                       check_vma=check)
         s = f(wp, center_idx, client_weights)
@@ -212,12 +216,12 @@ def _sharded_fused_round(local, mesh, axis, check, w, center_idx, *,
                    axis=axis)
     out_specs = stats_specs(axis)
     if client_weights is None:
-        f = shard_map(lambda wl, ci: body(wl, ci, None), mesh=mesh,
+        f = jax.shard_map(lambda wl, ci: body(wl, ci, None), mesh=mesh,
                       in_specs=(P(None, axis), P()), out_specs=out_specs,
                       check_vma=check)
         s = f(wp, center_idx)
     else:
-        f = shard_map(body, mesh=mesh,
+        f = jax.shard_map(body, mesh=mesh,
                       in_specs=(P(None, axis), P(), P()), out_specs=out_specs,
                       check_vma=check)
         s = f(wp, center_idx, client_weights)

@@ -74,7 +74,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: int | None = None,
                     scale: float | None = None,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool) -> jax.Array:
     """Tiled attention.  q: (B, Hq, Sq, Dh); k, v: (B, Hkv, Skv, Dh).
 
     Queries are aligned to the END of the K/V timeline (decode-friendly):

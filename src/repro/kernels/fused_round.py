@@ -16,6 +16,12 @@ exactly two streaming sweeps (see :mod:`repro.core.fused`):
       exactly once, like ``segment_mean``), while the client→barycenter
       distances for the medoid step accumulate into a resident (N, K) block.
 
+Both kernels take their distances in Gram form on rows *centered* per chunk
+(the chunk's column mean over clients subtracted from every row and every
+center or barycenter): distances are translation-invariant, and clients
+that start a round from one θ sit close together far from the origin, so
+uncentered ``‖w‖² + ‖c‖² − 2⟨w, c⟩`` would cancel away most of f32's digits.
+
 Grid: (D // block_d,) for both — the only axis is a reduction for the
 accumulators (constant output index_map) and a pure stream for the tiles.
 """
@@ -27,6 +33,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+#: full f32 MXU contraction (see ``repro.core.fused.mm``: the Gram form
+#: cancels catastrophically at the TPU's default bf16-pass precision)
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def _center_dist_kernel(w_ref, conehot_ref, out_ref):
     i = pl.program_id(0)
@@ -36,13 +46,14 @@ def _center_dist_kernel(w_ref, conehot_ref, out_ref):
         out_ref[...] = jnp.zeros_like(out_ref)
 
     wk = w_ref[...].astype(jnp.float32)              # (N, BD)
+    wk = wk - jnp.mean(wk, axis=0, keepdims=True)    # centered chunk
     ck = jax.lax.dot_general(                        # (K, BD) center rows,
         conehot_ref[...].astype(jnp.float32), wk,    # gathered on the MXU
         (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=_HIGHEST, preferred_element_type=jnp.float32)
     cross = jax.lax.dot_general(                     # (N, K)
         wk, ck, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=_HIGHEST, preferred_element_type=jnp.float32)
     wsq = jnp.sum(wk * wk, axis=1)
     csq = jnp.sum(ck * ck, axis=1)
     out_ref[...] += wsq[:, None] + csq[None, :] - 2.0 * cross
@@ -50,7 +61,7 @@ def _center_dist_kernel(w_ref, conehot_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
 def center_sq_dists(w: jax.Array, conehot: jax.Array, *, block_d: int = 16384,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool) -> jax.Array:
     """(N, D), (K, N) one-hot of center indices -> (N, K) squared distances.
 
     VMEM working set: (N + K)·block_d·4 for the chunk + centers, plus the
@@ -85,20 +96,22 @@ def _stats_kernel(m_ref, w_ref, b_ref, t_ref, d2_ref):
     m = m_ref[...].astype(jnp.float32)               # (K, N)
     bc = jax.lax.dot_general(                        # (K, BD) barycenter tile
         m, wk, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=_HIGHEST, preferred_element_type=jnp.float32)
     b_ref[...] = bc
     t_ref[...] = jnp.mean(bc, axis=0, keepdims=True)  # (1, BD) θ tile
+    r = jnp.mean(wk, axis=0, keepdims=True)           # chunk center
+    wc, bcc = wk - r, bc - r
     cross = jax.lax.dot_general(                     # (N, K)
-        wk, bc, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    wsq = jnp.sum(wk * wk, axis=1)
-    bsq = jnp.sum(bc * bc, axis=1)
+        wc, bcc, (((1,), (1,)), ((), ())),
+        precision=_HIGHEST, preferred_element_type=jnp.float32)
+    wsq = jnp.sum(wc * wc, axis=1)
+    bsq = jnp.sum(bcc * bcc, axis=1)
     d2_ref[...] += wsq[:, None] + bsq[None, :] - 2.0 * cross
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
 def fused_coalition_stats(w: jax.Array, m: jax.Array, *, block_d: int = 16384,
-                          interpret: bool = True,
+                          interpret: bool,
                           ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """One sweep: barycenters, θ, and medoid distances from a single read.
 

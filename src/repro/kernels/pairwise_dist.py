@@ -7,7 +7,10 @@ D-chunks; each grid step computes the chunk's Gram matrix on the MXU
 
     acc += ‖w_i‖² + ‖w_j‖² − 2·⟨w_i, w_j⟩
 
-into a resident (N, N) VMEM accumulator.  This is the TPU adaptation of the
+into a resident (N, N) VMEM accumulator, on rows centered per chunk (the
+chunk's column mean over clients subtracted — distances are
+translation-invariant, and uncentered client weights that share one θ would
+cancel away most of f32's digits).  This is the TPU adaptation of the
 paper's flatten-and-norm: distance becomes a bandwidth-bound streaming matmul
 instead of a materialised (N, N, D) difference tensor.
 
@@ -22,6 +25,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+#: full f32 MXU contraction (see ``repro.core.fused.mm``: the Gram form
+#: cancels catastrophically at the TPU's default bf16-pass precision)
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def _pairwise_kernel(w_ref, out_ref):
     i = pl.program_id(0)
@@ -31,16 +38,17 @@ def _pairwise_kernel(w_ref, out_ref):
         out_ref[...] = jnp.zeros_like(out_ref)
 
     wk = w_ref[...].astype(jnp.float32)              # (N, BD)
+    wk = wk - jnp.mean(wk, axis=0, keepdims=True)    # centered chunk
     gram = jax.lax.dot_general(                      # (N, N) on the MXU
         wk, wk, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=_HIGHEST, preferred_element_type=jnp.float32)
     sq = jnp.sum(wk * wk, axis=1)                    # (N,)
     out_ref[...] += sq[:, None] + sq[None, :] - 2.0 * gram
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
 def pairwise_sq_dists(w: jax.Array, *, block_d: int = 16384,
-                      interpret: bool = True) -> jax.Array:
+                      interpret: bool) -> jax.Array:
     """(N, D) -> (N, N) squared distances, tiled over D.
 
     VMEM working set: N*block_d*4 bytes for the chunk + N²*4 for the
@@ -73,10 +81,12 @@ def _to_points_kernel(w_ref, p_ref, out_ref):
         out_ref[...] = jnp.zeros_like(out_ref)
 
     wk = w_ref[...].astype(jnp.float32)              # (N, BD)
-    pk = p_ref[...].astype(jnp.float32)              # (K, BD)
+    r = jnp.mean(wk, axis=0, keepdims=True)          # chunk center
+    wk = wk - r
+    pk = p_ref[...].astype(jnp.float32) - r          # (K, BD)
     cross = jax.lax.dot_general(                     # (N, K)
         wk, pk, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=_HIGHEST, preferred_element_type=jnp.float32)
     wsq = jnp.sum(wk * wk, axis=1)
     psq = jnp.sum(pk * pk, axis=1)
     out_ref[...] += wsq[:, None] + psq[None, :] - 2.0 * cross
@@ -84,7 +94,7 @@ def _to_points_kernel(w_ref, p_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
 def sq_dists_to_points(w: jax.Array, p: jax.Array, *, block_d: int = 16384,
-                       interpret: bool = True) -> jax.Array:
+                       interpret: bool) -> jax.Array:
     """(N, D), (K, D) -> (N, K) squared distances, tiled over D."""
     n, d = w.shape
     k = p.shape[0]

@@ -13,18 +13,22 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+#: full f32 MXU contraction (see ``repro.core.fused.mm``: the Gram form
+#: cancels catastrophically at the TPU's default bf16-pass precision)
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def _segment_kernel(onehot_ref, w_ref, out_ref):
     oh = onehot_ref[...].astype(jnp.float32)          # (K, N)
     wk = w_ref[...].astype(jnp.float32)               # (N, BD)
     out_ref[...] = jax.lax.dot_general(
         oh, wk, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=_HIGHEST, preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
 def segment_sum(onehot: jax.Array, w: jax.Array, *, block_d: int = 16384,
-                interpret: bool = True) -> jax.Array:
+                interpret: bool) -> jax.Array:
     """(K, N) @ (N, D) -> (K, D), D-tiled."""
     k, n = onehot.shape
     d = w.shape[1]

@@ -11,8 +11,6 @@ initialises — the error messages say so rather than assuming a pod.
 """
 from __future__ import annotations
 
-import warnings
-
 import jax
 import numpy as np
 
@@ -24,10 +22,10 @@ def make_production_mesh(*, multi_pod: bool = False):
     (and FSDP-style expert sharding), ``model`` carries tensor parallelism,
     ``pod`` carries the cross-pod data-parallel replica.
 
-    When fewer devices exist than the pod shape wants, this *falls back to*
-    :func:`make_host_mesh` with a warning instead of raising, so examples and
-    docs run anywhere (the old exact-count requirement made every laptop run
-    a RuntimeError).
+    Raises ValueError when fewer devices exist than the pod shape wants: a
+    silent fall-back to a smaller mesh would run a "production" job on
+    whatever one host holds.  Use ``host`` or an explicit ``data=N`` spec for
+    a smaller mesh.
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
@@ -36,13 +34,11 @@ def make_production_mesh(*, multi_pod: bool = False):
     if len(devices) == n:
         return jax.make_mesh(shape, axes)
     if len(devices) < n:
-        warnings.warn(
-            f"need {n} devices for production mesh {shape}, have "
-            f"{len(devices)}; falling back to the host mesh "
-            "(set XLA_FLAGS=--xla_force_host_platform_device_count or run "
-            "under dryrun.py for the full shape)",
-            RuntimeWarning, stacklevel=2)
-        return make_host_mesh()
+        raise ValueError(
+            f"production mesh {shape} needs {n} devices, have "
+            f"{len(devices)}; use --mesh host or data=N for a smaller mesh "
+            "(on CPU set XLA_FLAGS=--xla_force_host_platform_device_count, "
+            "or run under dryrun.py for the full shape)")
     # more devices than needed (e.g. 512 placeholders, single-pod 256 mesh)
     arr = np.asarray(devices[:n]).reshape(shape)
     return jax.sharding.Mesh(arr, axes)

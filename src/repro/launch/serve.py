@@ -36,6 +36,7 @@ import numpy as np
 
 from repro.configs import get, reduced
 from repro.data import synthetic
+from repro.launch import compile_cache
 from repro.models import transformer as tf
 
 
@@ -232,6 +233,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main() -> None:
     args = build_parser().parse_args()
+    compile_cache.enable()
 
     if args.flash:
         from repro.models.layers import set_flash_kernel

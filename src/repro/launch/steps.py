@@ -101,15 +101,13 @@ def make_fl_round_step(loss_fn: Callable, template: PyTree, *, n_coalitions: int
     if shardmap_mesh is not None:
         from jax.sharding import PartitionSpec as P
 
-        from repro.compat import shard_map
-
         def spec0(tree):
             return jax.tree.map(
                 lambda l: P(client_axis, *([None] * (l.ndim - 1))), tree)
 
         def local_phase(client_params, client_batch):  # noqa: F811
             in_specs = (spec0(client_params), spec0(client_batch))
-            return shard_map(
+            return jax.shard_map(
                 lambda cp, cb: jax.vmap(one_client)(cp, cb),
                 mesh=shardmap_mesh, in_specs=in_specs,
                 out_specs=spec0(client_params))(client_params, client_batch)

@@ -42,6 +42,7 @@ from repro.core import sketch as sketch_mod
 from repro.core import strategies
 from repro.sim import attacks as sim_reg
 from repro.data import partition
+from repro.launch import compile_cache
 from repro.models import zoo as zoo_mod
 
 
@@ -501,6 +502,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main() -> None:
     args = build_parser().parse_args()
+    compile_cache.enable()
 
     if args.flash:
         from repro.models.layers import set_flash_kernel

@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import shard_map as _shard_map
 from repro.models.config import ModelConfig
 from repro.models.layers import dense_init
 
@@ -242,8 +241,8 @@ def moe_apply_ep(params: dict, cfg: ModelConfig, x: jax.Array, *, mesh,
     in_specs = (P(), P(expert_axis, None, model_axis),
                 P(expert_axis, None, model_axis),
                 P(expert_axis, model_axis, None), tok_spec)
-    fn = _shard_map(local_fn, mesh=mesh, in_specs=in_specs,
-                    out_specs=out_specs, check_vma=False)
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     out, aux = fn(params["router"], params["wi_gate"], params["wi_up"],
                   params["wo"], x)
     return out, aux

@@ -280,9 +280,11 @@ def test_parse_mesh_errors_mention_xla_flags():
     assert mesh_lib.mesh_spec(m) == "data=1"
 
 
-def test_production_mesh_falls_back_with_warning():
-    if DEVS >= 8:
-        pytest.skip("production mesh fits on a forced 8-device host")
-    with pytest.warns(RuntimeWarning, match="fall"):
-        m = mesh_lib.make_production_mesh()
-    assert "data" in m.axis_names
+def test_production_mesh_raises_when_too_few_devices():
+    """No silent fall-back: a 'production' mesh on too few devices (the
+    256-device pod shape on one host, or one TPU chip) is an error that
+    names the smaller meshes to use instead."""
+    with pytest.raises(ValueError, match="needs 256 devices.*data=N"):
+        mesh_lib.parse_mesh("production")
+    with pytest.raises(ValueError, match="needs 512 devices"):
+        mesh_lib.make_production_mesh(multi_pod=True)

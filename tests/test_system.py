@@ -170,3 +170,49 @@ def test_fl_round_step_shardmap_matches_gspmd():
     for l1, l2 in zip(jax.tree.leaves(p1), jax.tree.leaves(p2)):
         np.testing.assert_allclose(np.asarray(l1), np.asarray(l2),
                                    rtol=1e-4, atol=1e-5)
+
+
+# -- entry-point plumbing: compile cache + chip smoke ------------------------
+
+def test_compile_cache_dir_env_or_fixed_checkout_path(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing is set in code; without
+    it the cache lives at one fixed directory inside the checkout."""
+    import os
+
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert compile_cache.enable() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        want = os.path.join(root, ".jax_compile_cache")
+        assert compile_cache.enable() == compile_cache.DEFAULT_DIR == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+@pytest.mark.parametrize("alone", [False, True])
+def test_chip_smoke_fails_without_tpu(tmp_path, alone):
+    """No TPU (this CPU host), or no repo beside the script: a non-zero
+    exit and no result line."""
+    import os
+    import shutil
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    script = os.path.join(root, "chip_smoke.py")
+    if alone:
+        script = shutil.copy(script, tmp_path / "chip_smoke.py")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("PYTHONPATH", None)
+    proc = subprocess.run([sys.executable, str(script)], cwd=tmp_path,
+                          env=env, capture_output=True, text=True,
+                          timeout=120)
+    assert proc.returncode != 0
+    assert '"ok"' not in proc.stdout
+    assert ("No module named 'repro'" if alone else "no TPU") in proc.stderr
