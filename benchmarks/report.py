@@ -1,7 +1,6 @@
-"""Render EXPERIMENTS.md tables from the dry-run / roofline JSONL records.
+"""Render the EXPERIMENTS.md table from the dry-run JSONL records.
 
-  PYTHONPATH=src python -m benchmarks.report \
-      --roofline results_roofline_baseline.jsonl --dryrun results_dryrun_baseline.jsonl
+  PYTHONPATH=src python -m benchmarks.report --dryrun results_dryrun_baseline.jsonl
 """
 from __future__ import annotations
 
@@ -26,32 +25,6 @@ def load(path: str) -> list[dict]:
     return [r for r in out.values()
             if not (r.get("status") == "error"
                     and (r.get("arch"), r.get("shape")) in combos_ok)]
-
-
-def _fmt(x, width=9):
-    if x is None:
-        return " " * width
-    return f"{x:{width}.3e}"
-
-
-def roofline_table(rows: list[dict]) -> str:
-    lines = ["| arch | shape | compute (s) | memory (s) | collective (s) | "
-             "bottleneck | useful | status |",
-             "|---|---|---|---|---|---|---|---|"]
-    order = {"train_4k": 0, "prefill_32k": 1, "decode_32k": 2, "long_500k": 3}
-    for r in sorted(rows, key=lambda r: (r["arch"], order.get(r["shape"], 9))):
-        if r.get("status") == "ok":
-            lines.append(
-                f"| {r['arch']} | {r['shape']} | {r['compute_s']:.3e} | "
-                f"{r['memory_s']:.3e} | {r['collective_s']:.3e} | "
-                f"**{r['bottleneck']}** | {r['useful_ratio']:.3f} | ok |")
-        elif r.get("status") == "skipped":
-            lines.append(f"| {r['arch']} | {r['shape']} | — | — | — | — | — | "
-                         f"skipped: {r.get('reason', '')[:60]} |")
-        else:
-            lines.append(f"| {r['arch']} | {r['shape']} | — | — | — | — | — | "
-                         f"ERROR |")
-    return "\n".join(lines)
 
 
 def dryrun_table(rows: list[dict]) -> str:
@@ -80,15 +53,10 @@ def dryrun_table(rows: list[dict]) -> str:
 
 def main() -> None:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--roofline", default=None)
-    ap.add_argument("--dryrun", default=None)
+    ap.add_argument("--dryrun", required=True)
     args = ap.parse_args()
-    if args.roofline:
-        print("## Roofline (single-pod 16x16, L-extrapolated)\n")
-        print(roofline_table(load(args.roofline)))
-    if args.dryrun:
-        print("\n## Dry-run (raw compiled artifacts)\n")
-        print(dryrun_table(load(args.dryrun)))
+    print("## Dry-run (raw compiled artifacts)\n")
+    print(dryrun_table(load(args.dryrun)))
 
 
 if __name__ == "__main__":

@@ -70,6 +70,12 @@ hooks of :meth:`Federation.run`:
   :mod:`repro.obs` sink while the run is live; pure host-side consumption
   of scan outputs that already exist, so numerics are untouched.
 
+Every phase of a round runs under a ``jax.named_scope`` (``fl.local_phase``,
+``fl.w_build``, ``fl.coalition_round``, ``fl.eval``) and the driver's host
+work under ``fl.*`` profiler spans, so a profiler trace puts each device op
+and each device-idle gap down to a phase (docs/observability.md); neither
+changes the program's numerics.
+
 Two orthogonal scale axes decouple the engines from fleet size and from a
 single device (see docs/architecture.md "Sharded federation"):
 
@@ -107,6 +113,7 @@ from typing import Any, Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro import sim as sim_mod
 from repro.core import backends as bk
@@ -603,23 +610,37 @@ class Federation:
         ``ATTACK_STREAM`` fold of the round key — the client-update chain is
         untouched.
         """
-        if ids is not None:
-            client_data = jax.tree.map(lambda a: a[ids % a.shape[0]],
-                                       client_data)
-        adv = self._adv_row(ids)
-        if adv is not None:
-            client_data = self._attack.poison(client_data, adv)
-        ckeys = jax.random.split(key, self.cfg.n_clients)
-        new_params, losses = jax.vmap(
-            lambda d, k: client_update(self.loss_fn, global_params, d, k,
-                                       self.cfg.client)
-        )(client_data, ckeys)
-        w = pytree.client_matrix(new_params)
-        if adv is not None:
-            akey = jax.random.fold_in(key, sim_mod.ATTACK_STREAM)
-            theta = pytree.flatten(global_params)
-            w = self._attack.transform(w, theta, adv, akey)
+        with jax.named_scope("fl.local_phase"):
+            if ids is not None:
+                client_data = jax.tree.map(lambda a: a[ids % a.shape[0]],
+                                           client_data)
+            adv = self._adv_row(ids)
+            if adv is not None:
+                client_data = self._attack.poison(client_data, adv)
+            ckeys = jax.random.split(key, self.cfg.n_clients)
+            new_params, losses = jax.vmap(
+                lambda d, k: client_update(self.loss_fn, global_params, d, k,
+                                           self.cfg.client)
+            )(client_data, ckeys)
+        with jax.named_scope("fl.w_build"):
+            w = pytree.client_matrix(new_params)
+            if adv is not None:
+                akey = jax.random.fold_in(key, sim_mod.ATTACK_STREAM)
+                theta = pytree.flatten(global_params)
+                w = self._attack.transform(w, theta, adv, akey)
         return w, losses
+
+    def _aggregate(self, w, state, like, mask=None):
+        """``strategy.round`` over W and θ as a pytree shaped like ``like``:
+        the round's coalition phase (both W passes, barycenters, θ)."""
+        with jax.named_scope("fl.coalition_round"):
+            res = self.strategy.round(w, state, mask=mask)
+            return res, pytree.unflatten(res.theta, like)
+
+    def _eval(self, gp) -> jax.Array:
+        """The in-round eval of θ."""
+        with jax.named_scope("fl.eval"):
+            return self.eval_fn(gp)
 
     def _bary_of(self, res: RoundResult) -> jax.Array:
         """The (n_groups, D) per-group models this round produced.
@@ -666,11 +687,10 @@ class Federation:
         key, k0, kc = jax.random.split(key, 3)
         w0, losses0 = self._local_phase(init_params, client_data, k0, ids)
         state = self.strategy.init_state(kc, w0)
-        res = self.strategy.round(w0, state)
-        gp = pytree.unflatten(res.theta, init_params)
+        res, gp = self._aggregate(w0, state, init_params)
         # Round 0 has no previous round to compare against: churn and drift
         # are identically 0, entropy/radius are the census partition's own.
-        y0 = {"loss": jnp.mean(losses0), "acc": self.eval_fn(gp),
+        y0 = {"loss": jnp.mean(losses0), "acc": self._eval(gp),
               "assignment": res.metrics.assignment,
               "counts": res.metrics.counts,
               "churn": jnp.float32(0.0),
@@ -794,17 +814,14 @@ class Federation:
     # -- engine step programs (one scanned round / event) --------------------------
 
     def _step_scan(self, data):
-        strategy = self.strategy
-
         def step(carry: _ScanCarry, ids):
             # ``ids`` is the scanned-over cohort row in cohort mode, None
             # (no xs) on the dense path — where this step traces to exactly
             # the pre-cohort program.
             key, kr = jax.random.split(carry.key)
             w, losses = self._local_phase(carry.gp, data, kr, ids)
-            res = strategy.round(w, carry.state)
-            gp = pytree.unflatten(res.theta, carry.gp)
-            acc = self.eval_fn(gp)
+            res, gp = self._aggregate(w, carry.state, carry.gp)
+            acc = self._eval(gp)
             bary = self._bary_of(res)
             y = {"loss": jnp.mean(losses), "acc": acc,
                  "assignment": res.metrics.assignment,
@@ -849,9 +866,8 @@ class Federation:
             # full participation eff is all-ones and the masked round is
             # bit-identical to the synchronous one.
             eff = sim_mod.staleness_weights(tau, scfg.staleness_alpha)
-            res = strategy.round(buf, carry.state, mask=eff)
-            gp = pytree.unflatten(res.theta, carry.gp)
-            acc = self.eval_fn(gp)
+            res, gp = self._aggregate(buf, carry.state, carry.gp, mask=eff)
+            acc = self._eval(gp)
             # Participants' mean loss, phrased through the same jnp.mean
             # as the idealized engines (scale is exactly 1.0 at full
             # participation => bit-identical codegen).
@@ -925,9 +941,8 @@ class Federation:
             # all-simultaneous cohort reduces to the synchronous round.
             eff = sim_mod.staleness_weights(t_now - last_t,
                                             scfg.staleness_alpha)
-            res = strategy.round(buf, carry.state, mask=eff)
-            gp = pytree.unflatten(res.theta, carry.gp)
-            acc = self.eval_fn(gp)
+            res, gp = self._aggregate(buf, carry.state, carry.gp, mask=eff)
+            acc = self._eval(gp)
             m = deliver.astype(jnp.float32)
             scale = cfg.n_clients / jnp.maximum(jnp.sum(m), 1.0)
             loss = jnp.mean(losses * (m * scale))
@@ -1120,11 +1135,16 @@ class Federation:
                     snapshot_every=None, store=None,
                     ckpt_every=None, ckpt_dir=None, resume=False,
                     metrics_every=None, sink=None):
+        # Host spans (``fl.*``, the profiler's TraceMe: one inactive check
+        # each when no profiler runs) name what the host does between the
+        # device programs, so a trace can put each device-idle gap down to it.
         total = self._n_steps(name)
-        cohorts = self._cohort_schedule(key, total)
-        carry, y0 = getattr(self, f"_prologue_{self._spec_of(name)}")(
-            init_params, client_data, key,
-            None if cohorts is None else cohorts[0])
+        with TraceAnnotation("fl.cohort_schedule"):
+            cohorts = self._cohort_schedule(key, total)
+        with TraceAnnotation("fl.prologue"):
+            carry, y0 = getattr(self, f"_prologue_{self._spec_of(name)}")(
+                init_params, client_data, key,
+                None if cohorts is None else cohorts[0])
         parts = [jax.tree.map(lambda a: jnp.asarray(a)[None], y0)]
         r_done = 0
         restored = (self._restore_ckpt(ckpt_dir, name, carry, y0)
@@ -1135,15 +1155,18 @@ class Federation:
             # round-0 hooks (cadence fires at r=0: a consumer can start
             # serving the census model immediately)
             if self._fires(0, snapshot_every, total):
-                self._publish(store, name, 0, carry, y0)
+                with TraceAnnotation("fl.publish"):
+                    self._publish(store, name, 0, carry, y0)
             if self._fires(0, ckpt_every, total):
-                self._save_ckpt(ckpt_dir, name, 0, carry, parts)
+                with TraceAnnotation("fl.checkpoint"):
+                    self._save_ckpt(ckpt_dir, name, 0, carry, parts)
         if sink is not None:
-            sink.emit(self._run_meta_record(name, carry))
-            # covers round 0 on a fresh start; on resume the restored trace
-            # is re-emitted so the ledger is complete from round 0 whichever
-            # checkpoint the run picked up at
-            self._emit_rows(sink, parts[0], 0, metrics_every, total)
+            with TraceAnnotation("fl.emit"):
+                sink.emit(self._run_meta_record(name, carry))
+                # covers round 0 on a fresh start; on resume the restored
+                # trace is re-emitted so the ledger is complete from round 0
+                # whichever checkpoint the run picked up at
+                self._emit_rows(sink, parts[0], 0, metrics_every, total)
 
         if name == "python":
             boundaries = list(range(r_done + 1, total + 1))
@@ -1156,24 +1179,30 @@ class Federation:
         for r in boundaries:
             prog = self._chunk_program(name, r - r_done,
                                        cohort=cohorts is not None)
-            if cohorts is None:
-                carry, ys = prog(carry, client_data)
-            else:
-                carry, ys = prog(carry, client_data,
-                                 cohorts[r_done + 1:r + 1])
+            with TraceAnnotation("fl.dispatch"):
+                if cohorts is None:
+                    carry, ys = prog(carry, client_data)
+                else:
+                    carry, ys = prog(carry, client_data,
+                                     cohorts[r_done + 1:r + 1])
             parts.append(ys)
             if sink is not None:
-                self._emit_rows(sink, ys, r_done + 1, metrics_every, total)
+                with TraceAnnotation("fl.emit"):
+                    self._emit_rows(sink, ys, r_done + 1, metrics_every,
+                                    total)
             r_done = r
             if self._fires(r, snapshot_every, total):
-                row = jax.tree.map(lambda a: a[-1], ys)
-                self._publish(store, name, r, carry, row)
+                with TraceAnnotation("fl.publish"):
+                    row = jax.tree.map(lambda a: a[-1], ys)
+                    self._publish(store, name, r, carry, row)
             if self._fires(r, ckpt_every, total):
-                self._save_ckpt(ckpt_dir, name, r, carry, parts)
-        stacked = (parts[0] if len(parts) == 1 else
-                   jax.tree.map(lambda *xs: jnp.concatenate(xs), *parts))
-        trace = Trace(**stacked)
-        return carry.gp, History(trace=jax.device_get(trace))
+                with TraceAnnotation("fl.checkpoint"):
+                    self._save_ckpt(ckpt_dir, name, r, carry, parts)
+        with TraceAnnotation("fl.history"):
+            stacked = (parts[0] if len(parts) == 1 else
+                       jax.tree.map(lambda *xs: jnp.concatenate(xs), *parts))
+            trace = Trace(**stacked)
+            return carry.gp, History(trace=jax.device_get(trace))
 
     def run(self, init_params: PyTree, client_data: PyTree, key: jax.Array,
             *, engine: str | None = None,
@@ -1251,11 +1280,12 @@ class Federation:
                                  "(repro.obs.make_sink)")
         elif sink is not None:
             metrics_every = 1                   # a sink alone: every round
-        return self._run_driver(name, init_params, client_data, key,
-                                snapshot_every=snapshot_every, store=store,
-                                ckpt_every=ckpt_every, ckpt_dir=ckpt_dir,
-                                resume=resume, metrics_every=metrics_every,
-                                sink=sink)
+        with TraceAnnotation("fl.run"):
+            return self._run_driver(
+                name, init_params, client_data, key,
+                snapshot_every=snapshot_every, store=store,
+                ckpt_every=ckpt_every, ckpt_dir=ckpt_dir, resume=resume,
+                metrics_every=metrics_every, sink=sink)
 
 
 def run_federation(init_params: PyTree,

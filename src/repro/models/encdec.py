@@ -54,7 +54,7 @@ def encode(params, cfg: ModelConfig, modal: jax.Array, *,
 
     if remat:
         body = jax.checkpoint(body)
-    x, _ = tf._scan(body, x, enc["layers"])
+    x, _ = jax.lax.scan(body, x, enc["layers"])
     return rmsnorm(enc["ln_f"], x, cfg.norm_eps)
 
 
@@ -75,7 +75,7 @@ def forward(params, cfg: ModelConfig, batch, *,
 
     if remat:
         body = jax.checkpoint(body)
-    x, auxes = tf._scan(body, x, params["layers"])
+    x, auxes = jax.lax.scan(body, x, params["layers"])
     return tf._lm_logits(params, cfg, x), jnp.sum(auxes)
 
 

@@ -32,17 +32,6 @@ from repro.models.layers import (attention_apply, attention_init, dense_init,
 
 PyTree = Any
 
-# Layer-scan unrolling (int or True).  The roofline runner sets this to True
-# together with tiny n_layers so XLA's cost model (which counts a while-loop
-# body ONCE, regardless of trip count) sees every layer; production lowering
-# keeps the scan for O(1)-in-depth HLO.
-LAYER_SCAN_UNROLL: int | bool = 1
-
-
-def _scan(body, init, xs):
-    return jax.lax.scan(body, init, xs, unroll=LAYER_SCAN_UNROLL)
-
-
 # --- per-layer block ----------------------------------------------------------
 
 def block_init(key, cfg: ModelConfig, *, cross: bool = False) -> dict:
@@ -181,7 +170,7 @@ def forward(params, cfg: ModelConfig, batch, *,
 
     if remat:
         body = jax.checkpoint(body)
-    x, auxes = _scan(body, x, params["layers"])
+    x, auxes = jax.lax.scan(body, x, params["layers"])
     return _lm_logits(params, cfg, x), jnp.sum(auxes)
 
 
@@ -259,7 +248,7 @@ def _step(params, cfg: ModelConfig, x: jax.Array, cache, positions):
             new_st.update(new_ssm)
         return x, new_st
 
-    x, new_state = _scan(body, x, (params["layers"], layer_state))
+    x, new_state = jax.lax.scan(body, x, (params["layers"], layer_state))
     new_cache = dict(cache)
     new_cache.update(new_state)
     new_cache["index"] = idx + x.shape[1]
