@@ -8,6 +8,13 @@ fc2: 10 (class logits)
 Valid padding (PyTorch Conv2d default): 28 -> 24 -> 12 -> 8 -> 4, so the
 flattened feature is 4*4*64 = 1024.  Pure-functional: ``init`` -> params
 pytree, ``apply`` -> logits.
+
+Each conv block pools before its ReLU, ``relu(maxpool(conv(x) + b))``.
+Max commutes with the monotone ReLU and both forms route a window's
+gradient to its first max (a window whose max is <= 0 gets none, since
+``relu'(0) = 0``), so this is the paper's ``maxpool(relu(.))`` to the
+bit, in value and gradient, while the ReLU and its gradient run on the
+pooled map and no full-size ReLU output is written.
 """
 from __future__ import annotations
 
@@ -77,8 +84,8 @@ def _maxpool(x):
 
 def apply(params, x: jax.Array) -> jax.Array:
     """x: (B, 28, 28, 1) -> logits (B, 10)."""
-    h = _maxpool(jax.nn.relu(_conv(x, params["conv1"]["w"], params["conv1"]["b"])))
-    h = _maxpool(jax.nn.relu(_conv(h, params["conv2"]["w"], params["conv2"]["b"])))
+    h = jax.nn.relu(_maxpool(_conv(x, params["conv1"]["w"], params["conv1"]["b"])))
+    h = jax.nn.relu(_maxpool(_conv(h, params["conv2"]["w"], params["conv2"]["b"])))
     h = h.reshape(h.shape[0], -1)
     h = jax.nn.relu(h @ params["fc1"]["w"] + params["fc1"]["b"])
     return h @ params["fc2"]["w"] + params["fc2"]["b"]

@@ -7,6 +7,8 @@ CPU never checks.  The topology is described inside a module fixture, never
 at import: only one process may load the TPU library, and every pytest
 worker imports every test file.  Keep these tests in this one file.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -113,3 +115,31 @@ def test_sharded_round_compiles_on_described_mesh(topo, native_kernels,
                    ).lower(w, ci).compile().as_text()
     assert "all-reduce" in text
     assert (NATIVE in text) == (backend == "pallas")
+
+
+def test_cnn_local_step_pools_before_relu(one_chip):
+    """The paper CNN's vmapped local phase (N = 10 clients, batch 10, two
+    steps on a 20-digit shard) as the engine runs it.  Its conv blocks pool
+    before the ReLU, so no op of the compiled step applies a ReLU (or its
+    gradient) to a full-size 24x24 map.  The max-pools keep JAX's own
+    gradient, one ``select-and-scatter`` per pool: a first-max mask written
+    in its place measured slower on a v5e in every form tried, because its
+    view of the conv output forced a costlier relayout (PERF.md)."""
+    from repro.core.client import ClientConfig, client_update
+    from repro.models import cnn
+
+    params = jax.tree.map(lambda s: _sds(s.shape, one_chip, s.dtype),
+                          jax.eval_shape(lambda: cnn.init(jax.random.key(0))))
+    data = {"x": _sds((N, 20, 28, 28, 1), one_chip),
+            "y": _sds((N, 20), one_chip, jnp.int32)}
+    keys = _sds((N,), one_chip, jax.random.key(0).dtype)
+    ccfg = ClientConfig(epochs=1, batch_size=10, lr=0.01)
+    text = jax.jit(lambda p, d, k: jax.vmap(
+        lambda dd, kk: client_update(cnn.loss_fn, p, dd, kk, ccfg))(d, k)
+    ).lower(params, data, keys).compile().as_text()
+    full_size_relu = [line for line in text.splitlines()
+                      if "jit(relu)" in line
+                      and re.search(r"\[[0-9,]*24,24[,\]]", line)]
+    assert any("jit(relu)" in line for line in text.splitlines())
+    assert full_size_relu == []
+    assert text.count("select-and-scatter(") == 2
